@@ -46,7 +46,7 @@ import numpy as np
 
 from repro.cluster.faults import FaultInjector, FaultPlan
 from repro.core import scheduling as sch
-from repro.core.hardware import cpu_measured
+from repro.core import hardware
 from repro.core.perf_model import HardwareParams, PerfModel
 from repro.core.request import Kind, Phase, Request
 from repro.data.traces import TraceRequest
@@ -348,7 +348,6 @@ class PoolRuntime:
         self.clock = clock or WallClock()
         self.slo_ttft = slo_ttft
         self.slo_tpot = slo_tpot
-        self.pm = PerfModel(cfg, hw or cpu_measured())
         self.rng = random.Random(seed)
         self.seed = seed
         self.relaxed_decode_cap = relaxed_decode_cap
@@ -360,25 +359,33 @@ class PoolRuntime:
         if model is None:
             model = build_model(cfg, remat=False)
             params = model.init(jax.random.PRNGKey(seed))
-        self.model, self.params = model, params
+            # one engine per device (round-robin over the devices the
+            # engines use), each with its own copy of the weights and its KV
+            # pools committed there; KV migration goes through the host
+            replicas = [jax.device_put(params, d)
+                        for d in jax.devices()[:n_strict + n_relaxed]]
+        else:
+            replicas = [params]
+        self.model, self.params = model, replicas[0]
+        (device,) = jax.tree.leaves(self.params)[0].devices()
+        self.pm = PerfModel(cfg, hw or hardware.for_device(device))
         # engines in (and across) runtimes over the same weights share one
         # compiled-kernel set; pass runtime.kernel_donor to the next runtime
         donor: ServingEngine | None = kernels_from
         self.strict_pool: list[EngineSlot] = []
         self.relaxed_pool: list[EngineSlot] = []
-        for i in range(n_strict):
-            eng = ServingEngine(model, params, num_pages=num_pages,
-                                page_size=page_size, decode_buckets=decode_buckets,
+        for i in range(n_strict + n_relaxed):
+            eng = ServingEngine(model, replicas[i % len(replicas)],
+                                num_pages=num_pages, page_size=page_size,
+                                decode_buckets=decode_buckets,
                                 backend=backend, prefix_cache=self.prefix_cache,
                                 kernels_from=donor)
             donor = donor or eng
-            self.strict_pool.append(EngineSlot(f"strict{i}", "strict", eng))
-        for i in range(n_relaxed):
-            eng = ServingEngine(model, params, num_pages=num_pages,
-                                page_size=page_size, decode_buckets=decode_buckets,
-                                backend=backend, prefix_cache=self.prefix_cache,
-                                kernels_from=donor)
-            self.relaxed_pool.append(EngineSlot(f"relaxed{i}", "relaxed", eng))
+            if i < n_strict:
+                self.strict_pool.append(EngineSlot(f"strict{i}", "strict", eng))
+            else:
+                self.relaxed_pool.append(EngineSlot(
+                    f"relaxed{i - n_strict}", "relaxed", eng))
         self.kernel_donor = donor  # share compiled kernels across runtimes
         # queues hold (req, tokens[, home_slot]) — home pins a layer-
         # interrupted prefill to the engine holding its partial state

@@ -8,8 +8,13 @@ The paper profiles these on Ascend 910c; we provide:
   - ASCEND_910C: the paper's platform, reconstructed from public numbers
     (A100-class: ~400 TFLOP/s fp16 per chip in the 910c dual-die package ->
     ~each die ≈ A100) — used to sanity-check Figure 3 shapes.
-  - cpu_measured(): fitted from timed engine runs in this container
+  - cpu_measured(): fitted from timed engine runs on a CPU host
     (benchmarks/bench_perfmodel_accuracy.py writes the fit).
+
+``for_device`` picks the calibration for the device a runtime serves on:
+accelerators by their ``device_kind`` (a kind missing from
+``DEVICE_KINDS`` is an error, never a silent default), ``cpu_measured()``
+only on the CPU platform.
 """
 from __future__ import annotations
 
@@ -54,3 +59,22 @@ def cpu_measured(F: float = 50e9, M: float = 10e9, O_p: float = 30e-3,
         name="cpu", F_g=F, F_ap=F * 0.7, F_ad=F * 0.5, M_g=M, M_a=M,
         O_p=O_p, O_d=O_d, B_c=1e9, hbm_capacity=8e9, peak_flops=F,
         peak_hbm_bw=M)
+
+
+# jax ``Device.device_kind`` -> calibration
+DEVICE_KINDS = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def for_device(device) -> HardwareParams:
+    """Roofline calibration for a jax device (see module docstring)."""
+    if device.platform == "cpu":
+        return cpu_measured()
+    try:
+        return DEVICE_KINDS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware calibration for {device.platform} device kind "
+            f"{device.device_kind!r}; known kinds: {sorted(DEVICE_KINDS)}"
+        ) from None

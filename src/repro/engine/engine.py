@@ -43,7 +43,7 @@ The per-iteration hot path is allocation- and sync-free:
   and ``launch.serve --backend``.
 * ``k_pool``/``v_pool`` are **donated** through the jitted decode step and
   through the prefill KV scatter, so XLA writes the paged pools in place
-  instead of copying the full (L, num_pages, page, Hkv, hd) arrays every
+  instead of copying the full (L, num_pages, page, Hkv*hd) arrays every
   iteration. Prefill buffers each layer's K/V and lands the whole prefill
   in a single donated scatter (one more at each preemption point).
 * Sampling (greedy, or temperature/top-k via ``SamplingParams`` /
@@ -87,7 +87,9 @@ import numpy as np
 from repro.core.perf_model import PerfModel
 from repro.core.request import Phase, Request
 from repro.core.scheduling import split_chunk
-from repro.engine.kv_cache import PagedKVCache, transfer_checksum, verify_transfer
+from repro.engine.kv_cache import (PagedKVCache, head_major_pages, pool_rows,
+                                   token_major, transfer_checksum,
+                                   verify_transfer)
 
 
 class EngineCrashedError(RuntimeError):
@@ -239,8 +241,11 @@ class ServingEngine:
         self.params = params
         self.backend = resolve_backend(backend)
         self.sampling = sampling or SamplingParams()
+        # the KV pools live on the device that holds this engine's weights
+        (device,) = jax.tree.leaves(params)[0].devices()
         self.cache = PagedKVCache(cfg, num_pages, page_size,
-                                  enable_prefix_cache=prefix_cache)
+                                  enable_prefix_cache=prefix_cache,
+                                  device=device)
         self.decode_buckets = tuple(sorted(decode_buckets))
         self.perf_model = perf_model
         self.requests: dict[int, Request] = {}
@@ -254,15 +259,16 @@ class ServingEngine:
         self._prefill_bucketed = impl_for_backend(self.backend) == "xla"
         self.stats = EngineStats()
         if kernels_from is not None:
-            # Pool runtimes run N+M engines over the SAME weights; the jitted
-            # step functions only close over (model, cfg, page_size, backend),
-            # so sibling engines can share one compiled-kernel set instead of
+            # Pool runtimes run N+M engines over the same weights (one copy
+            # per device); the jitted step functions only close over (model,
+            # cfg, page_size, backend) and take the weights as arguments, so
+            # sibling engines can share one compiled-kernel set instead of
             # re-tracing/compiling per engine.
             src = kernels_from
-            assert (src.model is model and src.params is params
+            assert (src.model is model
                     and src.cache.page_size == page_size
                     and src.backend == self.backend), \
-                "kernel sharing requires identical model/params/page_size/backend"
+                "kernel sharing requires identical model/page_size/backend"
             self._layer_fn = src._layer_fn
             self._embed_fn = src._embed_fn
             self._logits_fn = src._logits_fn
@@ -271,7 +277,6 @@ class ServingEngine:
             self._mixed_fns = src._mixed_fns
             self._horizon_fns = src._horizon_fns
             self._mixed_horizon_fns = src._mixed_horizon_fns
-            self._layer_params_cached = src._layer_params_cached
         else:
             self._layer_fn = self._build_layer_fn()
             self._embed_fn = jax.jit(lambda p, t: model._embed(p, t))
@@ -281,10 +286,10 @@ class ServingEngine:
             self._mixed_fns: dict[tuple, Callable] = {}
             self._horizon_fns: dict[tuple, Callable] = {}
             self._mixed_horizon_fns: dict[tuple, Callable] = {}
-            # per-layer params sliced once (not jax.tree.map per layer per prefill)
-            self._layer_params_cached = [
-                jax.tree.map(lambda a, i=i: a[i], params["layers"])
-                for i in range(cfg.num_layers)]
+        # per-layer weight slices for the legacy layer-granular prefill(),
+        # built on its first call: a second device copy of every layer that
+        # the chunked path never needs
+        self._layer_params_cached: list | None = None
         self._base_key = jax.random.PRNGKey(self.sampling.seed)
         self._sample_step = 0
         self.alive = True
@@ -380,6 +385,10 @@ class ServingEngine:
         return layer_fn
 
     def _layer_params(self, i: int):
+        if self._layer_params_cached is None:
+            self._layer_params_cached = [
+                jax.tree.map(lambda a, i=i: a[i], self.params["layers"])
+                for i in range(self.cfg.num_layers)]
         return self._layer_params_cached[i]
 
     def add_request(self, req: Request, prompt_tokens: list[int]) -> None:
@@ -523,22 +532,16 @@ class ServingEngine:
                 # dtype (f32 on CPU — see PagedKVCache) for bit-parity with
                 # the native-dtype pool layout
                 kpool = kpool.at[li, page_ids, offs].set(
-                    k_new[:, 0].astype(cfg.jnp_dtype).astype(kpool.dtype))
+                    pool_rows(k_new[:, 0].astype(cfg.jnp_dtype), kpool))
                 vpool = vpool.at[li, page_ids, offs].set(
-                    v_new[:, 0].astype(cfg.jnp_dtype).astype(vpool.dtype))
+                    pool_rows(v_new[:, 0].astype(cfg.jnp_dtype), vpool))
                 q = layers.dense(lp["attn"]["wq"], h[:, 0]).reshape(
                     -1, cfg.num_heads, hd)
                 if cfg.qk_norm:
                     q = layers.rmsnorm(lp["attn"]["q_norm"], q, cfg.norm_eps)
                 q = layers.apply_rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-                # compact the layer's KV to just this batch's pages: a gather
-                # of B*P pages (+ renumbered tables) instead of slicing the
-                # full num_pages pool out of the carried buffer per layer
-                B, P = tables.shape
-                page = kpool.shape[2]
-                comp_k = kpool[li, tables].reshape(B * P, page, *kpool.shape[3:])
-                comp_v = vpool[li, tables].reshape(B * P, page, *vpool.shape[3:])
-                local_tables = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
+                comp_k, comp_v, local_tables = head_major_pages(
+                    kpool, vpool, li, tables, cfg.num_kv_heads)
                 a = paged_attention(q, comp_k, comp_v, local_tables, lengths,
                                     num_kv_heads=cfg.num_kv_heads,
                                     logit_softcap=cfg.attn_logit_softcap,
@@ -864,17 +867,17 @@ class ServingEngine:
                     k_new, v_new = attention.project_kv_for_cache(
                         lp["attn"], hdn, d_positions, cfg)
                     kpool = kpool.at[li, d_page, d_off].set(
-                        k_new[:, 0].astype(cfg.jnp_dtype).astype(kpool.dtype))
+                        pool_rows(k_new[:, 0].astype(cfg.jnp_dtype), kpool))
                     vpool = vpool.at[li, d_page, d_off].set(
-                        v_new[:, 0].astype(cfg.jnp_dtype).astype(vpool.dtype))
+                        pool_rows(v_new[:, 0].astype(cfg.jnp_dtype), vpool))
                 hc = _norm(cfg, lp["ln1"], xc)
                 qc, kc, vc = attention._project_qkv(
                     lp["attn"], hc, cfg, c_pos[None],
                     rope=not cfg.is_encoder_decoder)
                 kpool = kpool.at[li, c_page, c_off].set(
-                    kc[0].astype(cfg.jnp_dtype).astype(kpool.dtype))
+                    pool_rows(kc[0].astype(cfg.jnp_dtype), kpool))
                 vpool = vpool.at[li, c_page, c_off].set(
-                    vc[0].astype(cfg.jnp_dtype).astype(vpool.dtype))
+                    pool_rows(vc[0].astype(cfg.jnp_dtype), vpool))
                 # ---- decode attention: backend paged kernel ----
                 if with_decode:
                     q = layers.dense(lp["attn"]["wq"], hdn[:, 0]).reshape(
@@ -883,11 +886,8 @@ class ServingEngine:
                         q = layers.rmsnorm(lp["attn"]["q_norm"], q, cfg.norm_eps)
                     q = layers.apply_rope(q[:, None], d_positions[:, None],
                                           cfg.rope_theta)[:, 0]
-                    B, P = d_tables.shape
-                    page = kpool.shape[2]
-                    comp_k = kpool[li, d_tables].reshape(B * P, page, *kpool.shape[3:])
-                    comp_v = vpool[li, d_tables].reshape(B * P, page, *vpool.shape[3:])
-                    local = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
+                    comp_k, comp_v, local = head_major_pages(
+                        kpool, vpool, li, d_tables, cfg.num_kv_heads)
                     a = paged_attention(q, comp_k, comp_v, local, d_lengths,
                                         num_kv_heads=cfg.num_kv_heads,
                                         logit_softcap=cfg.attn_logit_softcap,
@@ -905,10 +905,8 @@ class ServingEngine:
                         m = _norm(cfg, lp["post_ln2"], m)
                     xd = xd + m
                 # ---- chunk attention over the request's landed pages ----
-                ck = kpool[li, c_tables].reshape(
-                    1, chunk_pages * page_size, *kpool.shape[3:])
-                cv = vpool[li, c_tables].reshape(
-                    1, chunk_pages * page_size, *vpool.shape[3:])
+                ck = token_major(kpool[li, c_tables], cfg.num_kv_heads)[None]
+                cv = token_major(vpool[li, c_tables], cfg.num_kv_heads)[None]
                 ac = attention.flash_attention_xla(
                     qc, ck, cv, causal=True, q_offset=c_start,
                     kv_lens=c_kv_len, logit_softcap=cfg.attn_logit_softcap)

@@ -1,7 +1,9 @@
 """Paged KV cache: block allocator + JAX page pools (vLLM-style, §2.1).
 
-The pool is a pair of (L, num_pages, page_size, Hkv, hd) arrays; per-request
-page lists (block tables) live Python-side in the engine. Non-contiguous
+The pool is a pair of (L, num_pages, page_size, Hkv*hd) arrays; per-request
+page lists (block tables) live Python-side in the engine. The helpers
+``pool_rows``, ``token_major`` and ``head_major_pages`` convert between that
+layout and the (.., Hkv, hd) K/V the model and kernels use. Non-contiguous
 paging is what makes continuous batching + preemption cheap: evicting a
 request is just returning its pages to the free list.
 
@@ -17,7 +19,7 @@ partial-page tails are recomputed, never shared.
 Hot-path note: every pool write goes through a *jitted, donated* scatter
 (``_scatter_layers``). Donation aliases the input pool buffers to the
 outputs, so XLA updates the pool in place instead of copying the full
-L × num_pages × page × Hkv × hd arrays on every prefill-layer write — the
+L × num_pages × page × (Hkv·hd) arrays on every prefill-layer write — the
 dominant cost of the un-donated seed path. Prefill additionally buffers all
 layers' K/V and lands them in a single scatter per prefill (or per
 preemption segment) instead of one dispatch per layer.
@@ -68,6 +70,33 @@ def verify_transfer(k, v, checksum: float, rtol: float = 1e-9) -> None:
             f"got {got!r}")
 
 
+def pool_rows(kv, pool):
+    """K or V (..., Hkv, hd) -> pool rows (..., Hkv*hd) in the pool's dtype."""
+    return kv.reshape(*kv.shape[:-2], -1).astype(pool.dtype)
+
+
+def token_major(pages, num_kv_heads: int):
+    """Gathered pool pages (..., n, page, Hkv*hd) -> (..., n*page, Hkv, hd)."""
+    return pages.reshape(*pages.shape[:-3], -1, num_kv_heads,
+                         pages.shape[-1] // num_kv_heads)
+
+
+def head_major_pages(k_pool, v_pool, layer, tables, num_kv_heads: int):
+    """Gather one layer's KV for a decode batch into compacted head-major
+    (B*P, Hkv, page, hd) buffers — the paged kernel's block layout — plus
+    the renumbered (B, P) tables. Gathering the batch's B*P pages, instead
+    of slicing the whole layer out of the pool, keeps the copy small."""
+    B, P = tables.shape
+    page = k_pool.shape[2]
+
+    def gather(pool):
+        return pool[layer, tables].reshape(
+            B * P, page, num_kv_heads, -1).swapaxes(1, 2)
+
+    local = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
+    return gather(k_pool), gather(v_pool), local
+
+
 @functools.partial(jax.jit, donate_argnums=(0, 1))
 def _scatter_layers(k_pool, v_pool, layer_ids, page_ids, offs, k, v):
     """Scatter S positions of n layers into donated pools in one op.
@@ -76,8 +105,8 @@ def _scatter_layers(k_pool, v_pool, layer_ids, page_ids, offs, k, v):
     pools come back aliased — callers must rebind and drop the old refs.
     """
     idx = (layer_ids[:, None], page_ids[None, :], offs[None, :])
-    k_pool = k_pool.at[idx].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[idx].set(v.astype(v_pool.dtype))
+    k_pool = k_pool.at[idx].set(pool_rows(k, k_pool))
+    v_pool = v_pool.at[idx].set(pool_rows(v, v_pool))
     return k_pool, v_pool
 
 
@@ -317,6 +346,7 @@ class PagedKVCache:
     num_pages: int
     page_size: int = 16
     enable_prefix_cache: bool = False
+    device: jax.Device | None = None   # None: the default device
     k_pool: jnp.ndarray = field(init=False)
     v_pool: jnp.ndarray = field(init=False)
     allocator: BlockAllocator = field(init=False)
@@ -326,22 +356,28 @@ class PagedKVCache:
 
     def __post_init__(self):
         cfg = self.cfg
+        # (L, pages, page, Hkv*hd): heads and head_dim share the minor
+        # dimension, so a page is (page, Hkv*hd) rows that fill the TPU's
+        # (8|16, 128) tiles. With (.., Hkv, hd) minor dims (4 x 64 for
+        # tinyllama) the chip pads or relayouts the whole pool, and the
+        # compiled decode step then holds several pool-sized temporaries.
         shape = (cfg.num_layers, self.num_pages, self.page_size,
-                 cfg.num_kv_heads, cfg.head_dim_)
+                 cfg.num_kv_heads * cfg.head_dim_)
         # Storage dtype: XLA CPU lowers 16-bit-float scatters to a scalar
         # emulation loop (~1000x slower than f32 — measured in
         # bench_decode_hotpath's development); on CPU we store the pool in
         # f32 but ROUND every value through cfg.jnp_dtype before storing, so
         # the cached bits (and therefore tokens) are identical to the
         # bf16-pool layout used on TPU.
+        device = self.device or jax.devices()[0]
         self.value_dtype = cfg.jnp_dtype
-        if (jax.default_backend() == "cpu"
+        if (device.platform == "cpu"
                 and jnp.dtype(cfg.jnp_dtype).itemsize < 4):
             self.storage_dtype = jnp.float32
         else:
             self.storage_dtype = cfg.jnp_dtype
-        self.k_pool = jnp.zeros(shape, self.storage_dtype)
-        self.v_pool = jnp.zeros(shape, self.storage_dtype)
+        self.k_pool = jnp.zeros(shape, self.storage_dtype, device=device)
+        self.v_pool = jnp.zeros(shape, self.storage_dtype, device=device)
         self.allocator = BlockAllocator(self.num_pages, reserved=1)
         self.prefix = (RadixPrefixCache(self.allocator, self.page_size)
                        if self.enable_prefix_cache else None)
@@ -438,11 +474,9 @@ class PagedKVCache:
     def export_request(self, rid: int) -> tuple[np.ndarray, np.ndarray, int]:
         """Gather a request's KV (for migration): (L, S, Hkv, hd) x2 + len."""
         table = np.asarray(self.tables[rid], np.int32)
-        L = self.cfg.num_layers
-        k = np.asarray(self.k_pool[:, table]).reshape(
-            L, -1, self.cfg.num_kv_heads, self.cfg.head_dim_)
-        v = np.asarray(self.v_pool[:, table]).reshape(
-            L, -1, self.cfg.num_kv_heads, self.cfg.head_dim_)
+        H = self.cfg.num_kv_heads
+        k = token_major(np.asarray(self.k_pool[:, table]), H)
+        v = token_major(np.asarray(self.v_pool[:, table]), H)
         n = self.lengths[rid]
         return k[:, :n], v[:, :n], n
 

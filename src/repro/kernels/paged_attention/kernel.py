@@ -12,6 +12,11 @@ accumulators persist in VMEM scratch across page iterations. Pages past the
 request length are skipped with pl.when (no HBM traffic is saved in
 interpret mode, but on TPU the pipeline still fetches — production uses
 num_pages-per-request grids; we keep the static bound and mask).
+
+The pool is head-major, (num_pages, Hkv, page_size, hd), so one K/V block
+is (1, 1, page_size, hd): Mosaic requires a block's last two dimensions to
+be multiples of the (8, 128) tile or equal to the array's own, and a
+(page_size, 1, hd) slice of a token-major pool breaks that for Hkv > 1.
 """
 from __future__ import annotations
 
@@ -23,9 +28,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def _kernel(lengths_ref, tables_ref,  # scalar prefetch
@@ -47,8 +49,8 @@ def _kernel(lengths_ref, tables_ref,  # scalar prefetch
     @pl.when(page_start < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)           # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)        # (page, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)           # (page, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, page)
         if logit_softcap:
             s = jnp.tanh(s / logit_softcap) * logit_softcap
@@ -73,12 +75,12 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
                            logit_softcap: float = 0.0, scale: float,
                            interpret: bool = False):
     """q: (B, Hkv, G, hd) — grouped query heads.
-    k_pages/v_pages: (num_pages, page_size, Hkv, hd) paged KV pool.
+    k_pages/v_pages: (num_pages, Hkv, page_size, hd) head-major paged KV.
     block_tables: (B, pages_per_req) int32 page ids (garbage past length ok).
     lengths: (B,) int32 valid tokens per request.
     Returns (B, Hkv, G, hd)."""
     B, Hkv, G, hd = q.shape
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[1]
+    page_size = k_pages.shape[2]
     pages_per_req = block_tables.shape[1]
 
     kernel = functools.partial(
@@ -90,10 +92,10 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
         grid=(B, Hkv, pages_per_req),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), lambda b, h, pi, lens, tabs: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, pi, lens, tabs: (tabs[b, pi], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, pi, lens, tabs: (tabs[b, pi], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, h, pi, lens, tabs: (tabs[b, pi], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, h, pi, lens, tabs: (tabs[b, pi], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, pi, lens, tabs: (b, h, 0, 0)),
         scratch_shapes=[
@@ -106,7 +108,7 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, block_tables, q, k_pages, v_pages)

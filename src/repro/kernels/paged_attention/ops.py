@@ -26,7 +26,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     num_kv_heads: int, logit_softcap: float = 0.0,
                     interpret: bool = False, use_ref: bool = False,
                     backend: str | None = None):
-    """q: (B, Hq, hd); pools (num_pages, page, Hkv, hd);
+    """q: (B, Hq, hd); head-major pools (num_pages, Hkv, page, hd);
     block_tables (B, P) int32; lengths (B,). Returns (B, Hq, hd)."""
     if backend is not None:
         use_ref, interpret = backend_flags(backend)

@@ -8,14 +8,14 @@ import numpy as np
 def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
                         logit_softcap: float = 0.0, scale: float | None = None):
     """Same signature/layout as the kernel: q (B, Hkv, G, hd),
-    pools (num_pages, page, Hkv, hd), tables (B, P), lengths (B,)."""
+    head-major pools (num_pages, Hkv, page, hd), tables (B, P), lengths (B,)."""
     B, Hkv, G, hd = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     P = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
     # gather this batch's pages -> contiguous (B, P*page, Hkv, hd)
-    k = k_pages[block_tables].reshape(B, P * page, Hkv, hd)
-    v = v_pages[block_tables].reshape(B, P * page, Hkv, hd)
+    k = k_pages[block_tables].swapaxes(2, 3).reshape(B, P * page, Hkv, hd)
+    v = v_pages[block_tables].swapaxes(2, 3).reshape(B, P * page, Hkv, hd)
     s = jnp.einsum("bhgd,bchd->bhgc", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if logit_softcap:

@@ -35,6 +35,7 @@ from repro.cluster.gateway import AdmissionRejected, Gateway, GatewayClosed
 from repro.cluster.runtime import PoolRuntime, WallClock
 from repro.configs import get_config
 from repro.core.request import Kind
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _response(status: str, body: bytes,
@@ -156,7 +157,9 @@ async def _handle(gateway: Gateway, reader: asyncio.StreamReader,
 
 
 def build_runtime(args) -> PoolRuntime:
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if not args.full_width:
+        cfg = cfg.reduced()
     return PoolRuntime(
         cfg, policy=args.policy, n_strict=args.strict,
         n_relaxed=args.relaxed, clock=WallClock(),
@@ -242,6 +245,10 @@ async def serve(args) -> int:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-7b")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the published config of --arch (random "
+                         "weights from --seed) instead of its reduced "
+                         "smoke-test variant")
     ap.add_argument("--policy", default="ooco")
     ap.add_argument("--backend", default="auto",
                     choices=["auto", "pallas", "interpret", "ref"])
@@ -281,4 +288,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main(sys.argv[1:]))

@@ -171,8 +171,6 @@ def lower_case(arch: str, shape_name: str, *, multi_pod: bool = False,
         "generated_code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
     }
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0] if ca else {}
     result["cost_analysis"] = {"flops": ca.get("flops"),
                                "bytes_accessed": ca.get("bytes accessed")}
     hlo = hlo_analysis.analyze(compiled.as_text())

@@ -1,4 +1,4 @@
-"""Serving launcher: real OOCO co-located serving on this host (CPU-scale).
+"""Serving launcher: real OOCO co-located serving on this host.
 
 Drives the pool-based runtime (``repro.cluster.runtime.PoolRuntime``):
 N latency-strict + M latency-relaxed ServingEngines, the OOCO scheduling
@@ -9,6 +9,9 @@ points (§3.4) routed through the roofline perf model, and a pluggable clock
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-7b --policy ooco \
       --strict 1 --relaxed 2 --virtual-clock --duration 20
+
+``--arch`` serves its reduced smoke-test variant unless ``--full-width``
+asks for the published config (the chip path; see chip_smoke.py).
 """
 from __future__ import annotations
 
@@ -22,6 +25,7 @@ from repro.cluster.runtime import (POLICIES, PoolRuntime, VirtualClock,
                                    WallClock, replay_hw)
 from repro.configs import get_config
 from repro.data import traces as tr
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def write_json_atomic(path: str, blob: str) -> None:
@@ -126,6 +130,10 @@ def _positive_int(knob):
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-7b")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the published config of --arch (random "
+                         "weights from --seed) instead of its reduced "
+                         "smoke-test variant")
     ap.add_argument("--policy", default="ooco", choices=list(POLICIES))
     ap.add_argument("--backend", default="auto",
                     choices=["auto", "pallas", "interpret", "ref"],
@@ -212,7 +220,9 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None):
     args = build_parser().parse_args(argv)
 
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if not args.full_width:
+        cfg = cfg.reduced()
     clock = VirtualClock() if args.virtual_clock else WallClock()
     hw = replay_hw(args.replay_hw) if args.virtual_clock else None
     chunk = args.chunk_tokens
@@ -243,4 +253,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(sys.argv[1:])

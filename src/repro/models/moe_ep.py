@@ -22,20 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 moved shard_map out of experimental
-    from jax import shard_map as _shard_map_mod
-    shard_map = _shard_map_mod.shard_map if hasattr(_shard_map_mod, "shard_map") \
-        else _shard_map_mod
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-# replication-check kwarg was renamed check_rep -> check_vma across jax
-# versions; resolve once at import (same pattern as kernels' _CompilerParams)
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(shard_map).parameters else "check_rep")
-
 from repro.models.config import ModelConfig
 
 
@@ -118,11 +104,11 @@ def moe_mlp_ep(p, x, cfg: ModelConfig, mesh, *, axis: str = "model",
             x_loc.dtype)
 
     tok_spec = P(tuple(token_axes), None)
-    fn = shard_map(device_fn, mesh=mesh,
-                   in_specs=(tok_spec, P(None, None),
-                             P(axis, None, None), P(axis, None, None),
-                             P(axis, None, None)),
-                   out_specs=tok_spec, **{_CHECK_KW: False})
+    fn = jax.shard_map(device_fn, mesh=mesh,
+                       in_specs=(tok_spec, P(None, None),
+                                 P(axis, None, None), P(axis, None, None),
+                                 P(axis, None, None)),
+                       out_specs=tok_spec, check_vma=False)
     out = fn(x.reshape(T, d), p["router"], p["gate"], p["up"], p["down"])
     return out.reshape(B, S, d)
 

@@ -11,6 +11,11 @@
   free enough and never include online work, mix-decoding batches never
   exceed the SLO bound under the perf model.
 """
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -195,6 +200,53 @@ class TestTopology:
         assert m["migrations"] > 0
         assert all(s.engine.stats.decode_steps > 0 for s in rt.strict_pool)
         assert all(s.engine.stats.prefill_tokens > 0 for s in rt.relaxed_pool)
+
+    def test_engines_spread_over_devices_keep_token_parity(self):
+        """With several devices, the runtime puts one engine on each (the
+        weights replicated, the KV pools committed there) and migrates KV
+        across devices; token streams equal the all-on-one-device replay.
+        Runs on four forced host devices in a CPU-only subprocess."""
+        code = """
+import json
+import jax
+from repro.cluster.runtime import PoolRuntime, VirtualClock, replay_hw
+from repro.configs import get_config
+from repro.data import traces as tr
+from repro.models.model import build_model
+
+cfg = get_config("qwen2.5-7b").reduced()
+model = build_model(cfg, remat=False)
+online = tr.online_trace("ooc", duration=4.0, mean_qps=2.0, seed=0)
+offline = tr.with_uniform_qps(tr.offline_requests(8, seed=1), 4.0)
+
+def replay(params):
+    rt = PoolRuntime(cfg, n_strict=1, n_relaxed=3, clock=VirtualClock(),
+                     backend="ref", num_pages=128, page_size=8,
+                     slo_ttft=1.0, slo_tpot=0.03, hw=replay_hw(),
+                     model=model if params is not None else None,
+                     params=params)
+    m = rt.run(online, offline, duration=4.0, max_prompt=32, max_output=6)
+    devs = [s.engine.cache.k_pool.devices().pop().id
+            for s in rt.strict_pool + rt.relaxed_pool]
+    return devs, rt.finished_signature(), m
+
+one = replay(model.init(jax.random.PRNGKey(0)))
+four = replay(None)
+print(json.dumps({"one_devs": one[0], "four_devs": four[0],
+                  "same": one[1] == four[1], "finished": len(four[1]),
+                  "migrations": four[2]["migrations"]}))
+"""
+        env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+               "HOME": os.environ.get("HOME", "/tmp"), "JAX_PLATFORMS": "cpu",
+               "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+        out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                             text=True, timeout=600, env=env)
+        assert out.returncode == 0, out.stderr[-3000:]
+        res = json.loads(out.stdout.strip().splitlines()[-1])
+        assert res["one_devs"] == [0, 0, 0, 0]
+        assert res["four_devs"] == [0, 1, 2, 3]
+        assert res["finished"] > 0 and res["migrations"] > 0
+        assert res["same"]
 
 
 # ---------------------------------------------------------------------------

@@ -52,8 +52,8 @@ def test_paged_attention_vs_ref(case, dtype, rng):
     B, Hq, Hkv, hd, page, P, npages, cap = case
     ks = jax.random.split(rng, 4)
     q = jax.random.normal(ks[0], (B, Hq, hd), dtype)
-    kp = jax.random.normal(ks[1], (npages, page, Hkv, hd), dtype)
-    vp = jax.random.normal(ks[2], (npages, page, Hkv, hd), dtype)
+    kp = jax.random.normal(ks[1], (npages, Hkv, page, hd), dtype)
+    vp = jax.random.normal(ks[2], (npages, Hkv, page, hd), dtype)
     tables = jax.random.randint(ks[3], (B, P), 0, npages)
     lengths = jax.random.randint(ks[3], (B,), 1, P * page + 1)
     out = paged_attention(q, kp, vp, tables, lengths, num_kv_heads=Hkv,
@@ -70,8 +70,8 @@ def test_paged_attention_ignores_garbage_past_length(rng):
     B, Hkv, hd, page, P, npages = 1, 2, 32, 8, 4, 16
     ks = jax.random.split(rng, 4)
     q = jax.random.normal(ks[0], (B, 2, hd))
-    kp = jax.random.normal(ks[1], (npages, page, Hkv, hd))
-    vp = jax.random.normal(ks[2], (npages, page, Hkv, hd))
+    kp = jax.random.normal(ks[1], (npages, Hkv, page, hd))
+    vp = jax.random.normal(ks[2], (npages, Hkv, page, hd))
     tables = jnp.array([[3, 7, 1, 2]], jnp.int32)
     lengths = jnp.array([11], jnp.int32)  # only pages 0-1 partially used
     out1 = paged_attention(q, kp, vp, tables, lengths, num_kv_heads=Hkv, use_ref=True)

@@ -17,15 +17,13 @@ from repro.models.moe_ep import moe_mlp_ep, moe_ep_ref, pad_experts
 
 cfg = dataclasses.replace(get_config("granite-moe-3b-a800m").reduced(),
                           moe_capacity_factor=8.0)  # no drops
-_axis_type = getattr(jax.sharding, "AxisType", None)  # newer jax only
-mesh = (jax.make_mesh((1, 2), ("data", "model"),
-                      axis_types=(_axis_type.Auto,) * 2)
-        if _axis_type is not None else jax.make_mesh((1, 2), ("data", "model")))
+mesh = jax.make_mesh((1, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p = moe_lib.init_moe(jax.random.PRNGKey(0), cfg)
 pp, E_pad = pad_experts(p, cfg, mesh.shape["model"])
 assert E_pad % 2 == 0
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.d_model), jnp.float32)
-with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
+with jax.set_mesh(mesh):
     out = moe_mlp_ep(pp, x, cfg, mesh)
 ref = moe_ep_ref(pp, x, cfg)
 err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))))

@@ -1,10 +1,13 @@
 """Roofline perf model tests (paper §3.3: Tables 2–4, Eq. 1)."""
+from types import SimpleNamespace
+
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import ASSIGNED, get_config
-from repro.core.hardware import TPU_V5E
+from repro.core.hardware import TPU_V5E, for_device
 from repro.core.perf_model import PerfModel
 
 ARCHS = ["qwen2.5-7b", "mixtral-8x22b", "rwkv6-1.6b", "zamba2-7b",
@@ -183,3 +186,25 @@ class TestSuggestChunkTokens:
     def test_tight_slo_returns_zero(self):
         pm = PerfModel(get_config("qwen2.5-7b"), TPU_V5E, tp=4)
         assert pm.suggest_chunk_tokens([4096] * 8, slo=1e-7) == 0
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", "tpu_v5e"),
+    ("cpu", "cpu", "cpu"),
+    ("tpu", "TPU v4", None),          # not in the table: an error
+    ("gpu", "NVIDIA H100", None),     # only the CPU gets cpu_measured()
+])
+def test_calibration_follows_device_kind(platform, kind, want):
+    device = SimpleNamespace(platform=platform, device_kind=kind)
+    if want is None:
+        with pytest.raises(ValueError, match="no hardware calibration"):
+            for_device(device)
+    else:
+        assert for_device(device).name == want
+
+
+def test_runtime_takes_calibration_from_its_device():
+    from repro.cluster.runtime import PoolRuntime
+    rt = PoolRuntime(get_config("qwen2.5-7b").reduced(), backend="ref",
+                     num_pages=16)
+    assert rt.pm.hw.name == for_device(jax.devices()[0]).name == "cpu"

@@ -44,18 +44,36 @@ def setup():
     return cfg, model, params
 
 
-def _ref_generate(model, params, prompt, n_new):
+def _ref_generate(model, params, prompt, n_new, margins=None):
+    """Greedy whole-model reference. If ``margins`` is a list, the top-2
+    logit gap of each generated position is appended to it."""
     import jax.numpy as jnp
     toks = list(prompt)
+
+    def take(logits):
+        if margins is not None:
+            top2 = jnp.sort(logits[0].astype(jnp.float32))[-2:]
+            margins.append(float(top2[1] - top2[0]))
+        toks.append(int(jnp.argmax(logits, -1)[0]))
+
     logits, cache = model.prefill(
         params, {"tokens": jnp.asarray([prompt], jnp.int32)},
         cache_len=len(prompt) + n_new)
-    toks.append(int(jnp.argmax(logits, -1)[0]))
+    take(logits)
     for _ in range(n_new - 1):
         logits, cache = model.decode_step(
             params, jnp.asarray([toks[-1]], jnp.int32), cache)
-        toks.append(int(jnp.argmax(logits, -1)[0]))
+        take(logits)
     return toks
+
+
+# Reference top-2 logit gap below which greedy parity with the whole-model
+# reference is not asserted. Both paths round logits to bf16, which tie
+# exactly on a few drawn prompts; the engine's chunked paged attention
+# rounds differently, so a tie may break the other way. Every flip seen (5
+# in 306 generated tokens over 51 drawn workloads) sat at a gap of 0.0;
+# 1e-2 is about 2.5 bf16 steps at unit scale.
+TIE_GAP = 1e-2
 
 
 # ---------------------------------------------------------------------------
@@ -416,7 +434,9 @@ class TestEngineWarmColdParity:
     @settings(max_examples=5, deadline=None)
     def test_property_cache_on_matches_cold_reference(self, setup, data):
         """Random shared-prefix workloads through one warm engine: every
-        greedy stream equals its cold whole-prompt reference."""
+        greedy stream equals its cold whole-prompt reference up to the
+        first near-tied reference token (``TIE_GAP``), and equals the same
+        engine run cold (cache off) bit for bit."""
         cfg, model, params = setup
         vocab = cfg.vocab_size
         rng = np.random.RandomState(
@@ -424,21 +444,33 @@ class TestEngineWarmColdParity:
         shared = list(rng.randint(0, vocab, 8 * data.draw(
             st.integers(1, 3), label="prefix_pages")))
         n_reqs = data.draw(st.integers(2, 4), label="n_reqs")
-        eng = ServingEngine(model, params, num_pages=96, page_size=8,
-                            prefix_cache=True)
-        for _ in range(n_reqs):
-            suffix = list(rng.randint(0, vocab,
-                                      int(rng.randint(1, 10))))
-            prompt = shared + suffix
-            ref = _ref_generate(model, params, prompt, 3)
+        warm = ServingEngine(model, params, num_pages=96, page_size=8,
+                             prefix_cache=True)
+        cold = ServingEngine(model, params, num_pages=96, page_size=8,
+                             kernels_from=warm)
+
+        def generate(eng, prompt):
             r = Request(Kind.OFFLINE, 0.0, len(prompt), 3)
             eng.add_request(r, prompt)
             while r.generated == 0:
                 eng.mixed_step([], r.rid, 8)
             while not r.done:
                 eng.decode_step([r.rid])
-            assert eng.token_buf[r.rid] == ref
-        assert eng.stats.prefix_hits >= n_reqs - 1
+            return eng.token_buf[r.rid].tolist()
+
+        for _ in range(n_reqs):
+            suffix = list(rng.randint(0, vocab,
+                                      int(rng.randint(1, 10))))
+            prompt = shared + suffix
+            margins: list[float] = []
+            ref = _ref_generate(model, params, prompt, 3, margins)
+            got = generate(warm, prompt)
+            assert got == generate(cold, prompt)
+            n = len(prompt) + next(
+                (i for i, m in enumerate(margins) if m < TIE_GAP), 3)
+            assert got[:n] == ref[:n]
+        assert warm.stats.prefix_hits >= n_reqs - 1
+        assert cold.stats.prefix_hits == 0
 
 
 # ---------------------------------------------------------------------------
