@@ -44,6 +44,7 @@ from dataclasses import dataclass
 
 from repro.cluster.runtime import AdmissionRejected, PoolRuntime, WallClock
 from repro.core.request import Kind, Phase, Request
+from repro.core.tracing import span
 
 __all__ = ["Gateway", "GatewayClosed", "TokenStream", "AdmissionRejected"]
 
@@ -146,7 +147,7 @@ class Gateway:
         try:
             while not self._stop.is_set():
                 self._wake.clear()
-                with self._lock:
+                with span("ooco.gateway.round"), self._lock:
                     worked = self.rt.step()
                     self._publish()
                     idle = (not worked and not self.rt.online_queue
@@ -162,18 +163,22 @@ class Gateway:
     def _publish(self) -> None:
         """Fan new tokens out to client queues; close streams whose request
         reached a terminal state. Called with the lock held."""
-        for st in list(self._streams.values()):
-            toks = self.rt.generated_tokens(st.rid)
-            while st.emitted < len(toks):
-                self._post(st, ("tok", int(toks[st.emitted])))
-                st.emitted += 1
-            phase = st.req.phase
-            if phase is Phase.FINISHED:
-                self._close_stream(st, "finished")
-            elif phase is Phase.CANCELLED:
-                self._close_stream(st, "deadline"
-                                   if st.req.cancel_reason == "deadline"
-                                   else "cancelled")
+        with span("ooco.gateway.publish", streams=len(self._streams)) as s:
+            sent = 0
+            for st in list(self._streams.values()):
+                toks = self.rt.generated_tokens(st.rid)
+                while st.emitted < len(toks):
+                    self._post(st, ("tok", int(toks[st.emitted])))
+                    st.emitted += 1
+                    sent += 1
+                phase = st.req.phase
+                if phase is Phase.FINISHED:
+                    self._close_stream(st, "finished")
+                elif phase is Phase.CANCELLED:
+                    self._close_stream(st, "deadline"
+                                       if st.req.cancel_reason == "deadline"
+                                       else "cancelled")
+            s.set_metadata(tokens=sent)
 
     def _close_stream(self, st: _StreamState, outcome: str) -> None:
         """Terminal event, exactly once per stream (guarded by ``closed``
@@ -212,7 +217,9 @@ class Gateway:
         toks = [int(t) for t in prompt_tokens]
 
         def _admit() -> Request:
-            with self._lock:
+            with span("ooco.gateway.lock_wait"):
+                self._lock.acquire()
+            try:
                 req = Request(kind, self.rt.clock.now(), len(toks),
                               max(int(max_new_tokens), 1),
                               ttft_deadline=ttft_deadline,
@@ -220,6 +227,8 @@ class Gateway:
                 self.rt.submit(req, toks)   # may raise; nothing registered yet
                 self._streams[req.rid] = _StreamState(req.rid, req, queue)
                 return req
+            finally:
+                self._lock.release()
 
         req = await asyncio.to_thread(_admit)
         self._wake.set()

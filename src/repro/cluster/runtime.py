@@ -49,6 +49,7 @@ from repro.core import scheduling as sch
 from repro.core import hardware
 from repro.core.perf_model import HardwareParams, PerfModel
 from repro.core.request import Kind, Phase, Request
+from repro.core.tracing import span
 from repro.data.traces import TraceRequest
 from repro.engine.engine import ServingEngine
 from repro.engine.kv_cache import TransferIntegrityError
@@ -478,23 +479,27 @@ class PoolRuntime:
         """One scheduling round across every pool. Returns True if any
         engine did work; virtual mode advances the clock by the modeled
         round duration (max across engines — pools run in parallel)."""
-        now = self.clock.now()
-        self._enforce_deadlines(now)
-        self._apply_faults(now)
-        self._admission = self._admission_state()
-        if self._admission != "admit":
-            self.metrics.degraded_rounds += 1
-            if self._admission == "shed":
-                self._shed_offline()
-        self._retry_placements()
-        costs = [self._relaxed_round(slot, now) for slot in self.relaxed_pool]
-        costs += [self._strict_round(slot, now) for slot in self.strict_pool]
-        self.metrics.rounds += 1
-        cost = max(costs, default=0.0)  # pools can crash away entirely
-        if cost > 0:
-            self.clock.advance(cost)
-            return True
-        return False
+        with span("ooco.rt.round"):
+            now = self.clock.now()
+            with span("ooco.rt.checks"):
+                self._enforce_deadlines(now)
+                self._apply_faults(now)
+                self._admission = self._admission_state()
+                if self._admission != "admit":
+                    self.metrics.degraded_rounds += 1
+                    if self._admission == "shed":
+                        self._shed_offline()
+                self._retry_placements()
+            costs = [self._relaxed_round(slot, now)
+                     for slot in self.relaxed_pool]
+            costs += [self._strict_round(slot, now)
+                      for slot in self.strict_pool]
+            self.metrics.rounds += 1
+            cost = max(costs, default=0.0)  # pools can crash away entirely
+            if cost > 0:
+                self.clock.advance(cost)
+                return True
+            return False
 
     # ------------------------------------------------------------------
     # live request lifecycle: cancel, deadlines, streaming, health, drain
@@ -814,7 +819,8 @@ class PoolRuntime:
         if self.chunked:
             # fused mixed round: the §3.4.1 boundary is the chunk, chosen
             # here — deterministic under both clocks, no mid-layer polling
-            pf = self._pick_chunk_prefill(slot)
+            with span("ooco.rt.admit", role=slot.role):
+                pf = self._pick_chunk_prefill(slot)
             cost = self._decode_slot(slot, now, relaxed=True, prefill=pf)
         else:
             cost = self._prefill_one(slot, now)
@@ -1034,11 +1040,14 @@ class PoolRuntime:
                 self.online_queue.insert(0, (req, toks))
                 return 0.0
             eng.add_request(req, toks)
-            est = self.pm.prefill_estimate([len(toks)]).latency
-            t0 = time.perf_counter()
-            eng.prefill(req.rid)
-            cost = self._prefill_cost(est, self.cfg.num_layers,
-                                      time.perf_counter() - t0)
+            pe = self.pm.prefill_estimate([len(toks)])
+            est = pe.latency
+            with self._dispatch_span(slot, "prefill_layer", 0, len(toks), 1,
+                                     pe):
+                t0 = time.perf_counter()
+                eng.prefill(req.rid)
+                dt = time.perf_counter() - t0
+            cost = self._prefill_cost(est, self.cfg.num_layers, dt)
             self.metrics.prefill_modeled_seconds += cost
             if req.first_token_time is None:
                 req.first_token_time = now + cost
@@ -1058,14 +1067,17 @@ class PoolRuntime:
         req, toks, home = entry
         if home is None:
             eng.add_request(req, toks)
-        est = self.pm.prefill_estimate([len(toks)]).latency
+        pe = self.pm.prefill_estimate([len(toks)])
+        est = pe.latency
         preempt = self._preempt_probe(slot, now, est) \
             if self.policy == "ooco" else None
         layers_before = req.prefill_layers_done
-        t0 = time.perf_counter()
-        status = eng.prefill(req.rid, should_preempt=preempt)
+        with self._dispatch_span(slot, "prefill_layer", 0, len(toks), 1, pe):
+            t0 = time.perf_counter()
+            status = eng.prefill(req.rid, should_preempt=preempt)
+            dt = time.perf_counter() - t0
         cost = self._prefill_cost(est, req.prefill_layers_done - layers_before,
-                                  time.perf_counter() - t0)
+                                  dt)
         self.metrics.prefill_modeled_seconds += cost
         if status == "preempted":
             req.phase = Phase.QUEUED
@@ -1207,55 +1219,59 @@ class PoolRuntime:
         charged to the virtual clock; and when the attempt budget is
         exhausted the request falls back to the recompute path (re-admitted
         from the prompt log) instead of being lost mid-transfer."""
-        eng = src.engine
-        k, v, n, checksum = eng.export_for_transfer(req.rid)
-        per_attempt = (self.pm.migration_seconds(req.context_len)
-                       if self.clock.virtual else 0.0)
-        cost = 0.0
-        for attempt in range(1, self.max_transfer_attempts + 1):
-            outcome = ("ok" if self.injector is None
-                       else self.injector.transfer_outcome(self.clock.now()))
-            cost += per_attempt
-            if outcome == "ok":
-                dst.engine.migrate_in(
-                    req.rid, req, eng.token_buf[req.rid], k, v, n,
-                    sampling=eng.req_sampling.pop(req.rid, None),
-                    checksum=checksum)
-                eng.commit_transfer_out(req.rid)
-                (dst.online if req.kind == Kind.ONLINE
-                 else dst.offline).append(req)
-                self.metrics.migrations += 1
-                return cost
-            if outcome == "corrupt":
-                # payload arrives bit-flipped: the destination checksum
-                # rejects it before any state lands, so the intact source
-                # copy simply re-sends
-                bad = np.array(k, copy=True)
-                bad.flat[0] = abs(bad.flat[0]) + 1.0
-                try:
+        with span("ooco.rt.migrate", tokens=req.context_len):
+            eng = src.engine
+            k, v, n, checksum = eng.export_for_transfer(req.rid)
+            per_attempt = (self.pm.migration_seconds(req.context_len)
+                           if self.clock.virtual else 0.0)
+            cost = 0.0
+            for attempt in range(1, self.max_transfer_attempts + 1):
+                outcome = ("ok" if self.injector is None
+                           else self.injector.transfer_outcome(
+                               self.clock.now()))
+                cost += per_attempt
+                if outcome == "ok":
                     dst.engine.migrate_in(
-                        req.rid, req, eng.token_buf[req.rid], bad, v, n,
-                        sampling=eng.req_sampling.get(req.rid),
+                        req.rid, req, eng.token_buf[req.rid], k, v, n,
+                        sampling=eng.req_sampling.pop(req.rid, None),
                         checksum=checksum)
-                    raise AssertionError("corrupt transfer went undetected")
-                except TransferIntegrityError:
-                    pass
-            self.metrics.migration_retries += 1
-            if attempt < self.max_transfer_attempts:
-                delay = self.injector.backoff_seconds(
-                    attempt, self.backoff_base)
-                if self.clock.virtual:
-                    cost += delay
-        # attempt budget exhausted: recompute fallback — release the source
-        # copy and re-admit from the frontend prompt log (greedy replay
-        # regenerates the same tokens; waste lands in recompute_tokens)
-        eng.cache.free(req.rid)
-        eng.requests.pop(req.rid, None)
-        eng.token_buf.pop(req.rid, None)
-        eng.req_sampling.pop(req.rid, None)
-        self.metrics.migration_recomputes += 1
-        self._readmit(req)
-        return cost
+                    eng.commit_transfer_out(req.rid)
+                    (dst.online if req.kind == Kind.ONLINE
+                     else dst.offline).append(req)
+                    self.metrics.migrations += 1
+                    return cost
+                if outcome == "corrupt":
+                    # payload arrives bit-flipped: the destination
+                    # checksum rejects it before any state lands, so the
+                    # intact source copy simply re-sends
+                    bad = np.array(k, copy=True)
+                    bad.flat[0] = abs(bad.flat[0]) + 1.0
+                    try:
+                        dst.engine.migrate_in(
+                            req.rid, req, eng.token_buf[req.rid], bad, v, n,
+                            sampling=eng.req_sampling.get(req.rid),
+                            checksum=checksum)
+                        raise AssertionError(
+                            "corrupt transfer went undetected")
+                    except TransferIntegrityError:
+                        pass
+                self.metrics.migration_retries += 1
+                if attempt < self.max_transfer_attempts:
+                    delay = self.injector.backoff_seconds(
+                        attempt, self.backoff_base)
+                    if self.clock.virtual:
+                        cost += delay
+            # attempt budget exhausted: recompute fallback — release the
+            # source copy and re-admit from the frontend prompt log (greedy
+            # replay regenerates the same tokens; waste lands in
+            # recompute_tokens)
+            eng.cache.free(req.rid)
+            eng.requests.pop(req.rid, None)
+            eng.token_buf.pop(req.rid, None)
+            eng.req_sampling.pop(req.rid, None)
+            self.metrics.migration_recomputes += 1
+            self._readmit(req)
+            return cost
 
     def _evict_from(self, slot: EngineSlot, need_tokens: float,
                     exclude: set[int] | None = None) -> None:
@@ -1305,14 +1321,16 @@ class PoolRuntime:
             # total relaxed-pool loss (crashes/promotions): strict engines
             # take over prefill so the cluster degrades instead of wedging
             if self.chunked:
-                pf = self._pick_chunk_prefill(slot)
+                with span("ooco.rt.admit", role=slot.role):
+                    pf = self._pick_chunk_prefill(slot)
             else:
                 pre = self._prefill_one(slot, now)
         cost, batch = self._decode_slot(slot, now + pre, relaxed=False,
                                         want_batch=True, prefill=pf)
         cost += pre
         if self.policy == "ooco" and batch:
-            pull = self._pull_migration(slot, batch)
+            with span("ooco.rt.pull", role=slot.role):
+                pull = self._pull_migration(slot, batch)
             # the pull's KV transfer rides the interconnect while the next
             # round's compute occupies the chips, so the round is charged
             # max(compute, transfer), not the sum (same overlap the
@@ -1401,12 +1419,83 @@ class PoolRuntime:
         """One engine round: decode batch + (chunked mode) a fused prefill
         chunk in the same dispatch. ``prefill`` is the ``(req, toks)`` entry
         chosen by ``_pick_chunk_prefill``."""
+        with span("ooco.rt.plan", role=slot.role):
+            planned = self._plan_slot(slot, relaxed, prefill)
+        if planned is None:
+            return (0.0, []) if want_batch else 0.0
+        batch, pf_req, chunk, horizon, est = planned
+        if (self.injector is not None
+                and self.injector.dispatch_stuck(slot.name, now)):
+            # injected wedge: the dispatch would never return; the watchdog
+            # kills it once the round exceeds watchdog_mult x the roofline-
+            # predicted latency, and the round retries from intact state
+            # (nothing was committed, so token parity is untouched)
+            self.metrics.watchdog_aborts += 1
+            cost = (est.latency * self.watchdog_mult
+                    if self.clock.virtual else 0.0)
+            return (cost, []) if want_batch else cost
+        slot.last_bottleneck = est.bottleneck
+        if not relaxed:
+            online_lat = (self.pm.decode_estimate(
+                [r.context_len for r in slot.online]).latency
+                if slot.online else 0.0)
+            slot.pressure = 0.9 * slot.pressure + 0.1 * min(
+                online_lat / self.slo_tpot, 1.0)
+        virtual = self.clock.virtual
+        before = [r.decode_time_sum for r in batch] if virtual else None
+        active = ([min(horizon, r.remaining) for r in batch]
+                  if horizon > 1 else None)
+        rids = [r.rid for r in batch]
+        kind = (("mixed_horizon" if horizon > 1 else "mixed_step") if chunk
+                else "decode_horizon" if horizon > 1 else "decode_step")
+        with self._dispatch_span(slot, kind, len(batch), chunk, horizon, est):
+            t0 = time.perf_counter()
+            if kind == "mixed_horizon":
+                slot.engine.mixed_horizon(rids, pf_req.rid, chunk, horizon)
+                self.metrics.mixed_horizon_rounds += 1
+            elif kind == "mixed_step":
+                slot.engine.mixed_step(rids, pf_req.rid, chunk)
+            elif kind == "decode_horizon":
+                slot.engine.decode_horizon(rids, horizon)
+                self.metrics.horizon_rounds += 1
+            else:
+                slot.engine.decode_step(rids)
+            dt = time.perf_counter() - t0
+        step_lat = est.latency if virtual else dt
+        if virtual:
+            # the engine charged measured wall time; replace with modeled
+            # time so TPOT metrics are bit-deterministic across replays
+            # (a horizon row is charged its amortized share of the fused
+            # dispatch — early-exit rows only for the steps they ran)
+            for i, (r, b) in enumerate(zip(batch, before)):
+                share = (active[i] / horizon) if active is not None else 1.0
+                r.decode_time_sum = b + est.latency * share
+        if not relaxed:
+            self.measured_tpot = 0.8 * self.measured_tpot + 0.2 * step_lat
+        for r in batch:
+            if r.done:
+                self._finish(r, slot.engine, now + step_lat)
+        cost = step_lat
+        if chunk:
+            cost += self._after_chunk(slot, pf_req, now, step_lat)
+        return (cost, batch) if want_batch else cost
+
+    def _dispatch_span(self, slot: EngineSlot, kind: str, rows: int,
+                       chunk: int, steps: int, est):
+        """The ``ooco.rt.dispatch`` span of one engine call, carrying the
+        perf model's estimate of it (``est``, a ``StepEstimate``)."""
+        return span("ooco.rt.dispatch", role=slot.role, kind=kind, rows=rows,
+                    chunk=chunk, K=steps, predicted_s=est.latency)
+
+    def _plan_slot(self, slot: EngineSlot, relaxed: bool, prefill):
+        """The round's dispatch on ``slot``: ``(batch, prefill request,
+        chunk tokens, horizon, estimate)``, or None when there is nothing
+        to dispatch."""
         slot.online = [r for r in slot.online if not r.done]
         slot.offline = [r for r in slot.offline if not r.done]
-        empty = ((0.0, []) if want_batch else 0.0)
         pf_req = prefill[0] if prefill is not None else None
         if not slot.online and not slot.offline and pf_req is None:
-            return empty
+            return None
         if self.chunked:
             plan = self._plan_round(slot, relaxed, pf_req)
             batch = self._fit_batch(slot, plan.decode)
@@ -1450,7 +1539,7 @@ class PoolRuntime:
                 # vLLM-style recompute preemption — drop the landed prefix
                 # so pinned prefills can never wedge the engine
                 self._abort_chunk_prefill(slot, prefill)
-            return empty
+            return None
         dec_ctx = [r.context_len for r in batch]
         if chunk and horizon > 1:
             # one dispatch overhead for the whole fused mixed horizon;
@@ -1479,58 +1568,7 @@ class PoolRuntime:
             est = self.pm.horizon_estimate(dec_ctx, horizon)
         else:
             est = self.pm.decode_estimate(dec_ctx)
-        if (self.injector is not None
-                and self.injector.dispatch_stuck(slot.name, now)):
-            # injected wedge: the dispatch would never return; the watchdog
-            # kills it once the round exceeds watchdog_mult x the roofline-
-            # predicted latency, and the round retries from intact state
-            # (nothing was committed, so token parity is untouched)
-            self.metrics.watchdog_aborts += 1
-            cost = (est.latency * self.watchdog_mult
-                    if self.clock.virtual else 0.0)
-            return (cost, []) if want_batch else cost
-        slot.last_bottleneck = est.bottleneck
-        if not relaxed:
-            online_lat = (self.pm.decode_estimate(
-                [r.context_len for r in slot.online]).latency
-                if slot.online else 0.0)
-            slot.pressure = 0.9 * slot.pressure + 0.1 * min(
-                online_lat / self.slo_tpot, 1.0)
-        virtual = self.clock.virtual
-        before = [r.decode_time_sum for r in batch] if virtual else None
-        active = ([min(horizon, r.remaining) for r in batch]
-                  if horizon > 1 else None)
-        t0 = time.perf_counter()
-        if chunk and horizon > 1:
-            slot.engine.mixed_horizon([r.rid for r in batch], pf_req.rid,
-                                      chunk, horizon)
-            self.metrics.mixed_horizon_rounds += 1
-        elif chunk:
-            slot.engine.mixed_step([r.rid for r in batch], pf_req.rid, chunk)
-        elif horizon > 1:
-            slot.engine.decode_horizon([r.rid for r in batch], horizon)
-            self.metrics.horizon_rounds += 1
-        else:
-            slot.engine.decode_step([r.rid for r in batch])
-        dt = time.perf_counter() - t0
-        step_lat = est.latency if virtual else dt
-        if virtual:
-            # the engine charged measured wall time; replace with modeled
-            # time so TPOT metrics are bit-deterministic across replays
-            # (a horizon row is charged its amortized share of the fused
-            # dispatch — early-exit rows only for the steps they ran)
-            for i, (r, b) in enumerate(zip(batch, before)):
-                share = (active[i] / horizon) if active is not None else 1.0
-                r.decode_time_sum = b + est.latency * share
-        if not relaxed:
-            self.measured_tpot = 0.8 * self.measured_tpot + 0.2 * step_lat
-        for r in batch:
-            if r.done:
-                self._finish(r, slot.engine, now + step_lat)
-        cost = step_lat
-        if chunk:
-            cost += self._after_chunk(slot, pf_req, now, step_lat)
-        return (cost, batch) if want_batch else cost
+        return batch, pf_req, chunk, horizon, est
 
     def _abort_chunk_prefill(self, slot: EngineSlot, entry) -> None:
         """Discard a pinned chunk prefill's landed prefix and requeue the
@@ -1599,12 +1637,13 @@ class PoolRuntime:
 
     # ------------------------------------------------------------------
     def _finish(self, req: Request, eng: ServingEngine, t: float) -> None:
-        req.phase = Phase.FINISHED
-        req.finish_time = t
-        self.tokens[req.rid] = eng.token_buf[req.rid].tolist()
-        self.finished.append(req)
-        if self.draining:
-            self.metrics.drained += 1
+        with span("ooco.rt.finish"):
+            req.phase = Phase.FINISHED
+            req.finish_time = t
+            self.tokens[req.rid] = eng.token_buf[req.rid].tolist()
+            self.finished.append(req)
+            if self.draining:
+                self.metrics.drained += 1
 
     # ------------------------------------------------------------------
     # trace-driven event loop
@@ -1725,6 +1764,12 @@ class PoolRuntime:
                                      for s in pools)),
             "horizon_rounds": self.metrics.horizon_rounds,
             "mixed_horizon_rounds": self.metrics.mixed_horizon_rounds,
+            # decode occupancy, per decode iteration: real rows against
+            # rows padded to the bucket, block-table pages the real rows
+            # hold against the pages the padded step fetches
+            **{k: int(sum(getattr(s.engine.stats, k) for s in pools))
+               for k in ("rows_dispatched", "rows_padded", "kv_pages_live",
+                         "kv_pages_fetched")},
             # dispatch counts per kind across all engines — amortization is
             # observable directly (a mixed_horizon dispatch covers K decode
             # steps AND K prefill sub-chunks), not just via host_syncs

@@ -75,6 +75,7 @@ per step and verifies pool donation from the lowered HLO;
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from dataclasses import dataclass, field
@@ -87,6 +88,7 @@ import numpy as np
 from repro.core.perf_model import PerfModel
 from repro.core.request import Phase, Request
 from repro.core.scheduling import split_chunk
+from repro.core.tracing import span, tracing
 from repro.engine.kv_cache import (PagedKVCache, head_major_pages, pool_rows,
                                    token_major, transfer_checksum,
                                    verify_transfer)
@@ -123,6 +125,16 @@ def sample_tokens(logits, key, temps, top_ks):
     scaled = masked / jnp.maximum(temps, 1e-6)[:, None]
     sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(temps > 0, sampled, greedy)
+
+
+def pages_read(start: list[int], steps: list[int], page_size: int) -> int:
+    """KV pages that decode rows read: row ``i`` runs ``steps[i]``
+    iterations from position ``start[i]``, and the iteration at position
+    ``p`` reads the ``p // page_size + 1`` pages that cover ``[0, p]``."""
+    def upto(n):   # the sum over positions p < n of p // page_size + 1
+        q, r = divmod(n, page_size)
+        return page_size * q * (q + 1) // 2 + r * (q + 1)
+    return sum(upto(s + n) - upto(s) for s, n in zip(start, steps))
 
 
 class TokenRing:
@@ -212,8 +224,16 @@ class EngineStats:
     host_syncs: int = 0       # device->host syncs on the token path
                               # (one per dispatch that returns tokens)
     horizon_steps: int = 0    # decode iterations run inside K>1 horizons
-    prefill_seconds: float = 0.0
-    decode_seconds: float = 0.0
+    # decode occupancy, summed over decode iterations (K of a K-step
+    # horizon): the iterations real rows ran against the rows padded to the
+    # bucket, and the KV pages those real row-iterations read against the
+    # pages the padded step fetches (bucket x the batch's page count padded
+    # to a power of two); a row that finishes mid-horizon rides masked and
+    # counts as padding from then on
+    rows_dispatched: int = 0
+    rows_padded: int = 0
+    kv_pages_live: int = 0
+    kv_pages_fetched: int = 0
     prefix_hits: int = 0      # prompts that claimed >= 1 cached prefix page
     cached_tokens: int = 0    # prompt tokens served from the prefix cache
     shared_pages: int = 0     # pages claimed via refcount bumps, cumulative
@@ -365,7 +385,7 @@ class ServingEngine:
         impl = impl_for_backend(self.backend)
 
         @jax.jit
-        def layer_fn(lp, x, positions, kv_lens):
+        def prefill_layer(lp, x, positions, kv_lens):
             h = _norm(cfg, lp["ln1"], x)
             a, (k, v) = attention.attn_prefill(
                 lp["attn"], h, positions, cfg, window=cfg.sliding_window,
@@ -382,7 +402,7 @@ class ServingEngine:
                 m = _norm(cfg, lp["post_ln2"], m)
             return x + m, k, v
 
-        return layer_fn
+        return prefill_layer
 
     def _layer_params(self, i: int):
         if self._layer_params_cached is None:
@@ -414,58 +434,64 @@ class ServingEngine:
         # (that would overwrite pages shared with sibling requests)
         assert rid not in self.chunk_state, \
             "legacy prefill() cannot resume a chunked/warm-started request"
-        t0 = time.perf_counter()
         req = self.requests[rid]
         cfg = self.cfg
-        if rid in self.partial:
-            part = self.partial.pop(rid)
-            x, start_layer, tokens = part.x, part.layer, part.tokens
-        else:
-            tokens = np.asarray(self.token_buf[rid][: req.prompt_len], np.int32)
-            self.cache.ensure(rid, req.prompt_len)
-            padded = tokens
-            if self._prefill_bucketed:
-                # pad to a bucket length; the padded keys are masked out by
-                # kv_lens below, so one trace serves every length in the
-                # bucket instead of retracing per unique prompt length
-                padded = np.zeros(self.pad_chunk(tokens.shape[0]), np.int32)
-                padded[: tokens.shape[0]] = tokens
-            x = self._embed_fn(self.params, jnp.asarray(padded)[None])
-            start_layer = 0
-        S = tokens.shape[0]
-        positions = jnp.arange(x.shape[1])[None]
-        kv_lens = jnp.asarray([S], jnp.int32)
+        with span("ooco.engine.args"):
+            if rid in self.partial:
+                part = self.partial.pop(rid)
+                x, start_layer, tokens = part.x, part.layer, part.tokens
+            else:
+                tokens = np.asarray(self.token_buf[rid][: req.prompt_len],
+                                    np.int32)
+                self.cache.ensure(rid, req.prompt_len)
+                padded = tokens
+                if self._prefill_bucketed:
+                    # pad to a bucket length; the padded keys are masked out
+                    # by kv_lens below, so one trace serves every length in
+                    # the bucket instead of retracing per unique prompt length
+                    padded = np.zeros(self.pad_chunk(tokens.shape[0]),
+                                      np.int32)
+                    padded[: tokens.shape[0]] = tokens
+                x = self._embed_fn(self.params, jnp.asarray(padded)[None])
+                start_layer = 0
+            S = tokens.shape[0]
+            positions = jnp.arange(x.shape[1])[None]
+            kv_lens = jnp.asarray([S], jnp.int32)
         req.phase = Phase.PREFILLING
         ks, vs = [], []   # per-layer KV buffered; flushed once per segment
-        for li in range(start_layer, cfg.num_layers):
-            x, k, v = self._layer_fn(self._layer_params(li), x, positions,
-                                     kv_lens)
-            ks.append(k[0, :S])
-            vs.append(v[0, :S])
-            req.prefill_layers_done = li + 1
-            if should_preempt is not None and li < cfg.num_layers - 1 and should_preempt():
-                self._flush_prefill_kv(rid, start_layer, ks, vs)
-                self.partial[rid] = PartialPrefill(rid, x, li + 1, tokens)
-                self.stats.preemptions += 1
-                self.stats.prefill_seconds += time.perf_counter() - t0
-                return "preempted"
-        self._flush_prefill_kv(rid, start_layer, ks, vs)
-        # first token from the last REAL hidden state, sampled on device
-        logits = self._logits_fn(self.params, x[:, S - 1])
-        temps, topks = self._sampling_arrays([rid], 1)
-        if temps[0] > 0:
-            key, step = self._next_key()
-            nxt = int(self._sample_fn(logits, jax.random.fold_in(key, step),
-                                      jnp.asarray(temps), jnp.asarray(topks))[0])
-        else:
-            nxt = int(jnp.argmax(logits, -1)[0])
-        self.token_buf[rid].append(nxt)
-        req.generated = 1
-        req.phase = Phase.DECODING
-        self.stats.prefill_tokens += S
-        self.stats.host_syncs += 1
-        self.stats.dispatches_by_kind["prefill"] += 1
-        self.stats.prefill_seconds += time.perf_counter() - t0
+        with self._launch(self._layer_fn, "prefill_layer"):
+            for li in range(start_layer, cfg.num_layers):
+                x, k, v = self._layer_fn(self._layer_params(li), x,
+                                         positions, kv_lens)
+                ks.append(k[0, :S])
+                vs.append(v[0, :S])
+                req.prefill_layers_done = li + 1
+                if (should_preempt is not None and li < cfg.num_layers - 1
+                        and should_preempt()):
+                    self._flush_prefill_kv(rid, start_layer, ks, vs)
+                    self.partial[rid] = PartialPrefill(rid, x, li + 1, tokens)
+                    self.stats.preemptions += 1
+                    return "preempted"
+            self._flush_prefill_kv(rid, start_layer, ks, vs)
+            # first token from the last REAL hidden state, sampled on device
+            logits = self._logits_fn(self.params, x[:, S - 1])
+            temps, topks = self._sampling_arrays([rid], 1)
+            if temps[0] > 0:
+                key, step = self._next_key()
+                nxt = self._sample_fn(logits, jax.random.fold_in(key, step),
+                                      jnp.asarray(temps),
+                                      jnp.asarray(topks))[0]
+            else:
+                nxt = jnp.argmax(logits, -1)[0]
+        with span("ooco.engine.sync"):
+            nxt = int(nxt)
+        with span("ooco.engine.commit"):
+            self.token_buf[rid].append(nxt)
+            req.generated = 1
+            req.phase = Phase.DECODING
+            self.stats.prefill_tokens += S
+            self.stats.host_syncs += 1
+            self.stats.dispatches_by_kind["prefill"] += 1
         return "done"
 
     def abort_prefill(self, rid: int) -> None:
@@ -502,6 +528,35 @@ class ServingEngine:
         """Pad a decode batch's page dimension to a power of two — bounds the
         set of (bucket, pages) jit variants. Shared with the benchmarks."""
         return 1 << (pages - 1).bit_length()
+
+    @contextlib.contextmanager
+    def _launch(self, fn, kind: str, *, rows: int = 0, bucket: int = 0,
+                pages: int = 0, positions: np.ndarray | None = None,
+                active: np.ndarray | None = None, steps: int = 1):
+        """The ``ooco.engine.launch`` span around calls of the jitted step
+        program ``fn`` (named ``kind``), after counting their decode
+        occupancy over the ``steps`` iterations: the padded step runs
+        ``bucket`` rows and fetches ``bucket * pages`` pages each
+        iteration; of the ``rows`` real rows, row ``i`` runs ``active[i]``
+        iterations (1 where ``active`` is None) from ``positions[i]`` and
+        reads the pages that cover its positions."""
+        n = [1] * rows if active is None else active[:rows].tolist()
+        row_steps = sum(n)
+        live = (pages_read(positions[:rows].tolist(), n, self.cache.page_size)
+                if rows else 0)
+        st = self.stats
+        st.rows_dispatched += row_steps
+        st.rows_padded += bucket * steps
+        st.kv_pages_live += live
+        st.kv_pages_fetched += bucket * pages * steps
+        with span("ooco.engine.launch", kind=kind, bucket=bucket, rows=rows,
+                  pages_padded=pages, K=steps, row_steps=row_steps,
+                  pages_live=live) as s:
+            compiled = fn._cache_size() if tracing() else None
+            yield
+            if compiled is not None:
+                # 1 where the calls traced and compiled (or loaded) a program
+                s.set_metadata(first_call=int(fn._cache_size() > compiled))
 
     def _decode_core(self):
         """One decode iteration over the layer stack — the computation
@@ -576,8 +631,8 @@ class ServingEngine:
         page_size = self.cache.page_size
 
         @functools.partial(jax.jit, donate_argnums=(5, 6))
-        def step(params, tokens, positions, tables, lengths, k_pool, v_pool,
-                 key, sample_step, temps, top_ks):
+        def decode_step(params, tokens, positions, tables, lengths, k_pool,
+                        v_pool, key, sample_step, temps, top_ks):
             page_ids = jnp.take_along_axis(
                 tables, (positions // page_size)[:, None], axis=1)[:, 0]
             offs = positions % page_size
@@ -591,8 +646,8 @@ class ServingEngine:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return nxt, k_pool, v_pool
 
-        self._decode_fns[key] = step
-        return step
+        self._decode_fns[key] = decode_step
+        return decode_step
 
     def _horizon_fn(self, bucket: int, pages: int, steps: int,
                     sampled: bool = False):
@@ -613,8 +668,8 @@ class ServingEngine:
         page_size = self.cache.page_size
 
         @functools.partial(jax.jit, donate_argnums=(4, 5))
-        def horizon(params, tokens, positions, tables, k_pool, v_pool,
-                    active_steps, key, first_step, temps, top_ks):
+        def decode_horizon(params, tokens, positions, tables, k_pool, v_pool,
+                           active_steps, key, first_step, temps, top_ks):
             def step_body(carry, t):
                 tokens, positions, kpool, vpool = carry
                 active = t < active_steps
@@ -641,8 +696,8 @@ class ServingEngine:
                 jnp.arange(steps, dtype=jnp.int32))
             return toks, k_pool, v_pool
 
-        self._horizon_fns[fkey] = horizon
-        return horizon
+        self._horizon_fns[fkey] = decode_horizon
+        return decode_horizon
 
     def decode_step(self, rids: list[int]) -> dict[int, int]:
         """One continuous-batching decode iteration for the given requests;
@@ -704,26 +759,31 @@ class ServingEngine:
                 self.req_sampling.pop(r, None)
         self.stats.decode_tokens += len(rids)
         self.stats.decode_steps += 1
-        self.stats.decode_seconds += dt
         return out
 
     def _decode_chunk(self, rids: list[int]) -> dict[int, int]:
         t0 = time.perf_counter()
-        bucket, pages, tokens, positions, tables, lengths = self._decode_args(rids)
-        temps, topks = self._sampling_arrays(rids, bucket)
-        sampled = (self.sampling.temperature > 0
-                   or any(r in self.req_sampling for r in rids))
-        fn = self._decode_fn(bucket, pages, sampled)
-        key, sample_step = self._next_key()
-        nxt_dev, self.cache.k_pool, self.cache.v_pool = fn(
-            self.params, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(lengths),
-            self.cache.k_pool, self.cache.v_pool,
-            key, sample_step, jnp.asarray(temps), jnp.asarray(topks))
-        nxt = np.asarray(nxt_dev)   # (bucket,) ids — the only device->host sync
-        self.stats.host_syncs += 1
-        self.stats.dispatches_by_kind["decode"] += 1
-        return self._decode_finish(rids, nxt, time.perf_counter() - t0)
+        with span("ooco.engine.args"):
+            bucket, pages, tokens, positions, tables, lengths = \
+                self._decode_args(rids)
+            temps, topks = self._sampling_arrays(rids, bucket)
+            sampled = (self.sampling.temperature > 0
+                       or any(r in self.req_sampling for r in rids))
+            fn = self._decode_fn(bucket, pages, sampled)
+            key, sample_step = self._next_key()
+        with self._launch(fn, "decode_step", rows=len(rids), bucket=bucket,
+                          pages=pages, positions=positions):
+            nxt_dev, self.cache.k_pool, self.cache.v_pool = fn(
+                self.params, jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(tables), jnp.asarray(lengths),
+                self.cache.k_pool, self.cache.v_pool,
+                key, sample_step, jnp.asarray(temps), jnp.asarray(topks))
+        with span("ooco.engine.sync"):
+            nxt = np.asarray(nxt_dev)   # (bucket,) ids — the only sync
+        with span("ooco.engine.commit"):
+            self.stats.host_syncs += 1
+            self.stats.dispatches_by_kind["decode"] += 1
+            return self._decode_finish(rids, nxt, time.perf_counter() - t0)
 
     # ------------------------------------------------------------------
     # multi-step decode horizons (K fused iterations, one host sync)
@@ -772,24 +832,43 @@ class ServingEngine:
 
     def _horizon_chunk(self, rids: list[int], steps: int) -> dict[int, list[int]]:
         t0 = time.perf_counter()
-        ahead = [min(steps, self.requests[r].remaining) for r in rids]
-        assert min(ahead) >= 1, "request already finished"
-        bucket, pages, tokens, positions, tables, _ = self._decode_args(
-            rids, claim_ahead=ahead)
-        active = np.zeros(bucket, np.int32)
-        active[: len(rids)] = ahead
-        temps, topks = self._sampling_arrays(rids, bucket)
-        sampled = (self.sampling.temperature > 0
-                   or any(r in self.req_sampling for r in rids))
-        fn = self._horizon_fn(bucket, pages, steps, sampled)
-        key, first_step = self._next_key_block(steps)
-        toks_dev, self.cache.k_pool, self.cache.v_pool = fn(
-            self.params, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(tables), self.cache.k_pool, self.cache.v_pool,
-            jnp.asarray(active), key, first_step,
-            jnp.asarray(temps), jnp.asarray(topks))
-        nxt = np.asarray(toks_dev)  # (steps, bucket) — the ONLY host sync
+        with span("ooco.engine.args"):
+            ahead = [min(steps, self.requests[r].remaining) for r in rids]
+            assert min(ahead) >= 1, "request already finished"
+            bucket, pages, tokens, positions, tables, _ = \
+                self._decode_args(rids, claim_ahead=ahead)
+            active = np.zeros(bucket, np.int32)
+            active[: len(rids)] = ahead
+            temps, topks = self._sampling_arrays(rids, bucket)
+            sampled = (self.sampling.temperature > 0
+                       or any(r in self.req_sampling for r in rids))
+            fn = self._horizon_fn(bucket, pages, steps, sampled)
+            key, first_step = self._next_key_block(steps)
+        with self._launch(fn, "decode_horizon", rows=len(rids),
+                          bucket=bucket, pages=pages, positions=positions,
+                          active=active, steps=steps):
+            toks_dev, self.cache.k_pool, self.cache.v_pool = fn(
+                self.params, jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(tables), self.cache.k_pool, self.cache.v_pool,
+                jnp.asarray(active), key, first_step,
+                jnp.asarray(temps), jnp.asarray(topks))
+        with span("ooco.engine.sync"):
+            nxt = np.asarray(toks_dev)  # (steps, bucket) — the ONLY sync
         dt = time.perf_counter() - t0
+        with span("ooco.engine.commit"):
+            out, total = self._horizon_rows(rids, active, nxt, dt, steps)
+            self.stats.decode_tokens += total
+            self.stats.decode_steps += steps
+            self.stats.horizon_steps += steps
+            self.stats.host_syncs += 1
+            self.stats.dispatches_by_kind["horizon"] += 1
+        return out
+
+    def _horizon_rows(self, rids: list[int], active: np.ndarray,
+                      nxt: np.ndarray, dt: float, steps: int):
+        """Per-request bookkeeping after a K-step horizon (decode or fused
+        mixed): row ``i`` emitted ``nxt[:active[i], i]``. Returns (rid ->
+        its new tokens, tokens emitted)."""
         out: dict[int, list[int]] = {}
         total = 0
         for i, r in enumerate(rids):
@@ -809,13 +888,7 @@ class ServingEngine:
                 req.phase = Phase.FINISHED
                 self.cache.free(r)
                 self.req_sampling.pop(r, None)
-        self.stats.decode_tokens += total
-        self.stats.decode_steps += steps
-        self.stats.horizon_steps += steps
-        self.stats.host_syncs += 1
-        self.stats.dispatches_by_kind["horizon"] += 1
-        self.stats.decode_seconds += dt
-        return out
+        return out, total
 
     # ------------------------------------------------------------------
     # fused mixed prefill/decode step (chunked prefill)
@@ -961,9 +1034,9 @@ class ServingEngine:
         with_decode = dec_bucket > 0
 
         @functools.partial(jax.jit, donate_argnums=(8, 9))
-        def step(params, d_tokens, d_positions, d_tables, d_lengths,
-                 c_tokens, c_meta, c_tables, k_pool, v_pool,
-                 key, sample_step, temps, top_ks):
+        def mixed_step(params, d_tokens, d_positions, d_tables, d_lengths,
+                       c_tokens, c_meta, c_tables, k_pool, v_pool,
+                       key, sample_step, temps, top_ks):
             # c_meta (2,) int32 = [start (tokens already landed), c_len]
             if with_decode:
                 d_page = jnp.take_along_axis(
@@ -982,8 +1055,8 @@ class ServingEngine:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return nxt, k_pool, v_pool
 
-        self._mixed_fns[fkey] = step
-        return step
+        self._mixed_fns[fkey] = mixed_step
+        return mixed_step
 
     def _mixed_horizon_fn(self, dec_bucket: int, dec_pages: int,
                           chunk_bucket: int, chunk_pages: int, steps: int,
@@ -1010,9 +1083,9 @@ class ServingEngine:
         with_decode = dec_bucket > 0
 
         @functools.partial(jax.jit, donate_argnums=(4, 5))
-        def horizon(params, d_tokens, d_positions, d_tables, k_pool, v_pool,
-                    active_steps, c_tokens, c_meta, c_tables, key,
-                    first_step, temps, top_ks):
+        def mixed_horizon(params, d_tokens, d_positions, d_tables, k_pool,
+                          v_pool, active_steps, c_tokens, c_meta, c_tables,
+                          key, first_step, temps, top_ks):
             def step_body(carry, inp):
                 d_toks, d_pos, kpool, vpool = carry
                 t, c_tok, c_m = inp
@@ -1045,8 +1118,8 @@ class ServingEngine:
                 (jnp.arange(steps, dtype=jnp.int32), c_tokens, c_meta))
             return toks, k_pool, v_pool
 
-        self._mixed_horizon_fns[fkey] = horizon
-        return horizon
+        self._mixed_horizon_fns[fkey] = mixed_horizon
+        return mixed_horizon
 
     def mixed_step(self, decode_rids: list[int], prefill_rid: int | None = None,
                    chunk_tokens: int = 0) -> dict[int, int]:
@@ -1103,6 +1176,54 @@ class ServingEngine:
     def _mixed_dispatch(self, rids: list[int], prid: int,
                         chunk_tokens: int) -> dict[int, int]:
         t0 = time.perf_counter()
+        with span("ooco.engine.args"):
+            req, state, c = self._chunk_state(prid, chunk_tokens)
+            # pages are claimed chunk-by-chunk, so a preempted prefill only
+            # ever holds capacity for what it has actually landed
+            self.cache.ensure(prid, state.done + c)
+            C = self.pad_chunk(c)
+            c_tok = np.zeros(C, np.int32)
+            c_tok[:c] = state.tokens[state.done: state.done + c]
+            cp, c_tables = self._chunk_table(prid)
+            c_meta = np.array([state.done, c], np.int32)
+            if rids:
+                bucket, pages, tokens, positions, tables, lengths = \
+                    self._decode_args(rids)
+            else:
+                bucket = pages = 0
+                tokens = positions = lengths = np.zeros(0, np.int32)
+                tables = np.zeros((0, 0), np.int32)
+            temps, topks = self._sampling_arrays(rids, bucket + 1)
+            d = (self.sampling.temperature, self.sampling.top_k)
+            temps[bucket], topks[bucket] = self.req_sampling.get(prid, d)
+            sampled = (self.sampling.temperature > 0
+                       or any(r in self.req_sampling for r in [*rids, prid]))
+            fn = self._mixed_fn(bucket, pages, C, cp, sampled)
+            key, sample_step = self._next_key()
+        with self._launch(fn, "mixed_step", rows=len(rids), bucket=bucket,
+                          pages=pages, positions=positions):
+            nxt_dev, self.cache.k_pool, self.cache.v_pool = fn(
+                self.params, jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(tables), jnp.asarray(lengths),
+                jnp.asarray(c_tok), jnp.asarray(c_meta),
+                jnp.asarray(c_tables), self.cache.k_pool, self.cache.v_pool,
+                key, sample_step, jnp.asarray(temps), jnp.asarray(topks))
+        with span("ooco.engine.sync"):
+            nxt = np.asarray(nxt_dev)   # (bucket + 1,) — single host sync
+        dt = time.perf_counter() - t0
+        with span("ooco.engine.commit"):
+            self.stats.host_syncs += 1
+            out = self._decode_finish(rids, nxt, dt) if rids else {}
+            self.stats.prefill_chunks += 1
+            self.stats.dispatches_by_kind["mixed" if rids else "prefill"] += 1
+            if rids:
+                self.stats.mixed_steps += 1
+            self._chunk_landed(req, state, c, int(nxt[-1]))
+        return out
+
+    def _chunk_state(self, prid: int, chunk_tokens: int):
+        """The request, its chunked-prefill state (claiming a cached prefix
+        on first sight) and the chunk length ``c`` this dispatch lands."""
         req = self.requests[prid]
         state = self.chunk_state.get(prid)
         if state is None:
@@ -1119,66 +1240,41 @@ class ServingEngine:
         c = min(int(chunk_tokens), req.prompt_len - state.done)
         assert c >= 1, "prefill already complete"
         req.phase = Phase.PREFILLING
-        # pages are claimed chunk-by-chunk, so a preempted prefill only ever
-        # holds capacity for what it has actually landed
-        self.cache.ensure(prid, state.done + c)
-        C = self.pad_chunk(c)
-        c_tok = np.zeros(C, np.int32)
-        c_tok[:c] = state.tokens[state.done: state.done + c]
+        return req, state, c
+
+    def _chunk_table(self, prid: int):
+        """The prefilling request's block table padded to a power of two."""
         table = self.cache.tables[prid]
         cp = self.pad_pages(len(table))
         c_tables = np.zeros(cp, np.int32)
         c_tables[: len(table)] = table
-        c_meta = np.array([state.done, c], np.int32)
-        if rids:
-            bucket, pages, tokens, positions, tables, lengths = \
-                self._decode_args(rids)
-        else:
-            bucket, pages = 0, 0
-            tokens = positions = lengths = np.zeros(0, np.int32)
-            tables = np.zeros((0, 0), np.int32)
-        temps, topks = self._sampling_arrays(rids, bucket + 1)
-        d = (self.sampling.temperature, self.sampling.top_k)
-        temps[bucket], topks[bucket] = self.req_sampling.get(prid, d)
-        sampled = (self.sampling.temperature > 0
-                   or any(r in self.req_sampling for r in [*rids, prid]))
-        fn = self._mixed_fn(bucket, pages, C, cp, sampled)
-        key, sample_step = self._next_key()
-        nxt_dev, self.cache.k_pool, self.cache.v_pool = fn(
-            self.params, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(lengths),
-            jnp.asarray(c_tok), jnp.asarray(c_meta), jnp.asarray(c_tables),
-            self.cache.k_pool, self.cache.v_pool,
-            key, sample_step, jnp.asarray(temps), jnp.asarray(topks))
-        nxt = np.asarray(nxt_dev)   # (bucket + 1,) — single host sync
-        self.stats.host_syncs += 1
-        dt = time.perf_counter() - t0
-        out = self._decode_finish(rids, nxt, dt) if rids else {}
+        return cp, c_tables
+
+    def _chunk_landed(self, req: Request, state: ChunkedPrefill, c: int,
+                      first_token: int) -> None:
+        """Bookkeeping after a dispatch landed ``c`` prompt tokens; when the
+        prompt is complete, ``first_token`` (the chunk row's sample) is the
+        first generated token."""
+        prid = req.rid
         state.done += c
         req.prefill_tokens_done = state.done
-        self.stats.prefill_chunks += 1
-        self.stats.dispatches_by_kind["mixed" if rids else "prefill"] += 1
-        if rids:
-            self.stats.mixed_steps += 1
-        else:
-            self.stats.prefill_seconds += dt
-        if state.done >= req.prompt_len:
-            self.token_buf[prid].append(int(nxt[-1]))
-            req.generated = 1
-            req.phase = Phase.DECODING
-            self.stats.prefill_tokens += req.prompt_len
-            if self.cache.prefix is not None:
-                # publish the full pages into the radix tree (refcount bump
-                # per adopted page) so later prompts can reuse them; the
-                # partial tail page stays private
-                self.cache.prefix.insert(
-                    state.tokens.tolist(), self.cache.tables[prid])
-            del self.chunk_state[prid]
-            if req.done:   # one-output request: finished at prefill
-                req.phase = Phase.FINISHED
-                self.cache.free(prid)
-                self.req_sampling.pop(prid, None)
-        return out
+        if state.done < req.prompt_len:
+            return
+        self.token_buf[prid].append(first_token)
+        req.generated = 1
+        req.phase = Phase.DECODING
+        self.stats.prefill_tokens += req.prompt_len
+        if self.cache.prefix is not None:
+            # publish the full pages into the radix tree (refcount bump per
+            # adopted page) so later prompts can reuse them; the partial
+            # tail page stays private
+            self.cache.prefix.insert(
+                state.tokens.tolist(), self.cache.tables[prid])
+        del self.chunk_state[prid]
+        if req.done:   # one-output request: finished at prefill
+            req.phase = Phase.FINISHED
+            self.cache.free(prid)
+            self.req_sampling.pop(prid, None)
 
     # ------------------------------------------------------------------
     # fused mixed-horizon dispatch (chunk + K decode iterations, one sync)
@@ -1248,115 +1344,71 @@ class ServingEngine:
                                 chunk_tokens: int,
                                 steps: int) -> dict[int, list[int]]:
         t0 = time.perf_counter()
-        req = self.requests[prid]
-        state = self.chunk_state.get(prid)
-        if state is None:
-            assert prid not in self.partial, \
-                "request already mid layer-granular prefill"
-            self.claim_prefix(prid)
-            state = self.chunk_state.get(prid)
-        if state is None:
-            state = self.chunk_state[prid] = ChunkedPrefill(
-                prid, np.asarray(self.token_buf[prid][: req.prompt_len],
-                                 np.int32))
-        c = min(int(chunk_tokens), req.prompt_len - state.done)
-        assert c >= 1, "prefill already complete"
+        req, state, c = self._chunk_state(prid, chunk_tokens)
         steps = min(steps, c)   # every sub-chunk must carry >= 1 token
         if steps <= 1:
             return {r: [t] for r, t in
                     self._mixed_dispatch(rids, prid, c).items()}
-        req.phase = Phase.PREFILLING
-        # the WHOLE horizon's chunk is claimed up front (claim-ahead to the
-        # horizon end); sub-chunks land into it iteration by iteration
-        self.cache.ensure(prid, state.done + c)
-        subs = split_chunk(c, steps)
-        C = self.pad_chunk(max(subs))
-        c_toks = np.zeros((steps, C), np.int32)
-        c_meta = np.zeros((steps, 2), np.int32)
-        pos = state.done
-        for i, s in enumerate(subs):
-            c_toks[i, :s] = state.tokens[pos: pos + s]
-            c_meta[i] = (pos, s)
-            pos += s
-        table = self.cache.tables[prid]
-        cp = self.pad_pages(len(table))
-        c_tables = np.zeros(cp, np.int32)
-        c_tables[: len(table)] = table
-        if rids:
-            ahead = [min(steps, self.requests[r].remaining) for r in rids]
-            assert min(ahead) >= 1, "request already finished"
-            bucket, pages, tokens, positions, tables, _ = self._decode_args(
-                rids, claim_ahead=ahead)
-        else:
-            ahead = []
-            bucket, pages = 0, 0
-            tokens = positions = np.zeros(0, np.int32)
-            tables = np.zeros((0, 0), np.int32)
-        active = np.zeros(bucket, np.int32)
-        active[: len(rids)] = ahead
-        temps, topks = self._sampling_arrays(rids, bucket + 1)
-        d = (self.sampling.temperature, self.sampling.top_k)
-        temps[bucket], topks[bucket] = self.req_sampling.get(prid, d)
-        sampled = (self.sampling.temperature > 0
-                   or any(r in self.req_sampling for r in [*rids, prid]))
-        fn = self._mixed_horizon_fn(bucket, pages, C, cp, steps, sampled)
-        key, first_step = self._next_key_block(steps)
-        toks_dev, self.cache.k_pool, self.cache.v_pool = fn(
-            self.params, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(tables), self.cache.k_pool, self.cache.v_pool,
-            jnp.asarray(active), jnp.asarray(c_toks), jnp.asarray(c_meta),
-            jnp.asarray(c_tables), key, first_step,
-            jnp.asarray(temps), jnp.asarray(topks))
-        nxt = np.asarray(toks_dev)  # (steps, bucket + 1) — the ONLY sync
-        self.stats.host_syncs += 1
-        self.stats.dispatches_by_kind["mixed_horizon"] += 1
+        with span("ooco.engine.args"):
+            # the WHOLE horizon's chunk is claimed up front (claim-ahead to
+            # the horizon end); sub-chunks land into it iteration by
+            # iteration
+            self.cache.ensure(prid, state.done + c)
+            subs = split_chunk(c, steps)
+            C = self.pad_chunk(max(subs))
+            c_toks = np.zeros((steps, C), np.int32)
+            c_meta = np.zeros((steps, 2), np.int32)
+            pos = state.done
+            for i, s in enumerate(subs):
+                c_toks[i, :s] = state.tokens[pos: pos + s]
+                c_meta[i] = (pos, s)
+                pos += s
+            cp, c_tables = self._chunk_table(prid)
+            if rids:
+                ahead = [min(steps, self.requests[r].remaining) for r in rids]
+                assert min(ahead) >= 1, "request already finished"
+                bucket, pages, tokens, positions, tables, _ = \
+                    self._decode_args(rids, claim_ahead=ahead)
+            else:
+                ahead = []
+                bucket = pages = 0
+                tokens = positions = np.zeros(0, np.int32)
+                tables = np.zeros((0, 0), np.int32)
+            active = np.zeros(bucket, np.int32)
+            active[: len(rids)] = ahead
+            temps, topks = self._sampling_arrays(rids, bucket + 1)
+            d = (self.sampling.temperature, self.sampling.top_k)
+            temps[bucket], topks[bucket] = self.req_sampling.get(prid, d)
+            sampled = (self.sampling.temperature > 0
+                       or any(r in self.req_sampling for r in [*rids, prid]))
+            fn = self._mixed_horizon_fn(bucket, pages, C, cp, steps, sampled)
+            key, first_step = self._next_key_block(steps)
+        with self._launch(fn, "mixed_horizon", rows=len(rids),
+                          bucket=bucket, pages=pages, positions=positions,
+                          active=active, steps=steps):
+            toks_dev, self.cache.k_pool, self.cache.v_pool = fn(
+                self.params, jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(tables), self.cache.k_pool, self.cache.v_pool,
+                jnp.asarray(active), jnp.asarray(c_toks),
+                jnp.asarray(c_meta), jnp.asarray(c_tables), key, first_step,
+                jnp.asarray(temps), jnp.asarray(topks))
+        with span("ooco.engine.sync"):
+            nxt = np.asarray(toks_dev)  # (steps, bucket + 1) — the ONLY sync
         dt = time.perf_counter() - t0
-        out: dict[int, list[int]] = {}
-        total = 0
-        for i, r in enumerate(rids):
-            rq = self.requests[r]
-            a = int(active[i])
-            toks = [int(x) for x in nxt[:a, i]]
-            buf = self.token_buf[r]
-            for tok in toks:
-                buf.append(tok)
-            rq.generated += a
-            # the horizon's wall time amortizes over its steps; a row that
-            # exits early is only charged for the steps it ran
-            rq.decode_time_sum += dt * a / steps
-            total += a
-            out[r] = toks
-            if rq.done:
-                rq.phase = Phase.FINISHED
-                self.cache.free(r)
-                self.req_sampling.pop(r, None)
-        state.done += c
-        req.prefill_tokens_done = state.done
-        self.stats.prefill_chunks += steps
-        if rids:
-            self.stats.decode_tokens += total
-            self.stats.decode_steps += steps
-            self.stats.horizon_steps += steps
-            self.stats.mixed_steps += steps
-            self.stats.decode_seconds += dt
-        else:
-            self.stats.prefill_seconds += dt
-        if state.done >= req.prompt_len:
+        with span("ooco.engine.commit"):
+            self.stats.host_syncs += 1
+            self.stats.dispatches_by_kind["mixed_horizon"] += 1
+            out, total = self._horizon_rows(rids, active, nxt, dt, steps)
+            self.stats.prefill_chunks += steps
+            if rids:
+                self.stats.decode_tokens += total
+                self.stats.decode_steps += steps
+                self.stats.horizon_steps += steps
+                self.stats.mixed_steps += steps
             # sub-chunks all carry >= 1 token and sum to c, so the prompt
             # can only complete at the FINAL iteration — its chunk-row
             # sample is the first generated token
-            self.token_buf[prid].append(int(nxt[-1, bucket]))
-            req.generated = 1
-            req.phase = Phase.DECODING
-            self.stats.prefill_tokens += req.prompt_len
-            if self.cache.prefix is not None:
-                self.cache.prefix.insert(
-                    state.tokens.tolist(), self.cache.tables[prid])
-            del self.chunk_state[prid]
-            if req.done:   # one-output request: finished at prefill
-                req.phase = Phase.FINISHED
-                self.cache.free(prid)
-                self.req_sampling.pop(prid, None)
+            self._chunk_landed(req, state, c, int(nxt[-1, bucket]))
         return out
 
     # ------------------------------------------------------------------
