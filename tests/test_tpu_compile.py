@@ -7,6 +7,8 @@ topology is described inside a module-scoped fixture (never at import):
 only one process at a time may load the TPU compiler library, and every
 test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -50,6 +52,14 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _assert_paged_kernel(compiled):
+    """The paged decode kernel is one ``tpu_custom_call`` instruction named
+    ``paged_attention``: the name the benchmark's roofline reader sums."""
+    names = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       compiled.as_text())
+    assert [n.rsplit(".", 1)[0] for n in names] == ["paged_attention"], names
+
+
 @pytest.mark.parametrize("arch", list(WIDTHS))
 def test_paged_decode_compiles_for_v5e(arch, one_chip):
     Hq, Hkv, hd = WIDTHS[arch]
@@ -61,7 +71,26 @@ def test_paged_decode_compiles_for_v5e(arch, one_chip):
         _spec((B, P), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip),
         num_kv_heads=Hkv)
-    _assert_kernel(lowered.compile())
+    _assert_paged_kernel(lowered.compile())
+
+
+@pytest.mark.parametrize("P", [1, 1024])
+@pytest.mark.parametrize("arch", list(WIDTHS))
+def test_paged_decode_compiles_at_extreme_tables_for_v5e(arch, P, one_chip):
+    """A decode bucket of 8 at the offline cell's shortest and longest
+    padded tables (1 and 1,024 pages): the kernel's block of pages is
+    clamped to the table, and it stays one custom call the roofline reader
+    can find by name."""
+    Hq, Hkv, hd = WIDTHS[arch]
+    B, page = 8, 16
+    lowered = paged_attention.lower(
+        _spec((B, Hq, hd), jnp.bfloat16, one_chip),
+        _spec((B * P, Hkv, page, hd), jnp.bfloat16, one_chip),
+        _spec((B * P, Hkv, page, hd), jnp.bfloat16, one_chip),
+        _spec((B, P), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+        num_kv_heads=Hkv)
+    _assert_paged_kernel(lowered.compile())
 
 
 @pytest.mark.parametrize("arch", list(WIDTHS))
